@@ -79,17 +79,17 @@ class ServiceClient:
     """One registered client: a named WindowStream plus its query options.
 
     Created by :meth:`QueryService.register` — not directly. The client
-    owns the admitted-window buffer (``stream``), the completed results
-    (``results``: window → converged values) and its admission→completion
-    latencies; the service owns scheduling. ``horizon`` is the last
-    snapshot index the client may ever query (defaults to the store's
-    final snapshot): launch anchors widen to it, which keeps successive
-    anchors nested so anchor maintenance stays incremental. A live
-    ``feed`` (``ingest.LiveWindowFeed``) makes the horizon grow instead:
-    each service turn polls the feed and admits windows the watermark has
-    born, widening ``horizon`` to their newest snapshot — a horizon jump
-    makes the previous anchor non-covering, so the next launch soundly
-    rebuilds (or hops to) the wider anchor.
+    owns the admitted-window buffer (``stream``) and the completed results
+    (``results``: window → converged values); the service owns scheduling
+    and the admission→completion latencies (``ServiceMetrics``).
+    ``horizon`` is the last snapshot index the client may ever query
+    (defaults to the store's final snapshot): launch anchors widen to it,
+    which keeps successive anchors nested so anchor maintenance stays
+    incremental. A live ``feed`` (``ingest.LiveWindowFeed``) makes the
+    horizon grow instead: each service turn polls the feed and admits
+    windows the watermark has born, widening ``horizon`` to their newest
+    snapshot — a horizon jump makes the previous anchor non-covering, so
+    the next launch soundly rebuilds (or hops to) the wider anchor.
     """
 
     name: str
@@ -110,7 +110,6 @@ class ServiceClient:
     feed: "object | None" = None
     results: "dict[Window, jnp.ndarray]" = dataclasses.field(
         default_factory=dict)
-    latencies_s: "list[float]" = dataclasses.field(default_factory=list)
     campaigns_done: int = 0
     _arrived: "dict[Window, float]" = dataclasses.field(default_factory=dict)
 
@@ -518,9 +517,10 @@ class QueryService:
 
         Acquires anchor state per distinct query key (hit/hop/rebuild via
         the "AS" cache), records chain links + progress, maps each lane to
-        its query's state (``lane_map``), and scatters results/latencies
-        back to the owning clients. The campaign boundary: the single
-        host sync per launch happens inside ``_slide_launch``.
+        its query's state (``lane_map``), scatters results back to the
+        owning clients and records their latencies. The campaign boundary:
+        the single host sync per launch, and the copies of its edge work
+        and sweeps, happen inside ``_slide_launch``.
         """
         anchor = (min(w[0] for _, campaign in chunk for w in campaign),
                   max(client.horizon for client, _ in chunk))
@@ -559,7 +559,7 @@ class QueryService:
                 owners.append(client)
                 lane_map.append(state_idx[client.qkey])
         lead = chunk[0][0]
-        res, bucket = _slide_launch(
+        res, bucket, stats = _slide_launch(
             self.store, lead.semiring, anchor_view, states, windows, anchor,
             max_iters=lead.max_iters, gated=lead.gated,
             track_parents=lead.track_parents, mesh=self.mesh,
@@ -567,25 +567,23 @@ class QueryService:
         done = time.perf_counter()
         for lane, (wnd, client) in enumerate(zip(windows, owners)):
             client.results[wnd] = res.values[lane]
-            latency = done - client._arrived.pop(wnd, done)
-            client.latencies_s.append(latency)
-            self._metrics.latencies_s.append(latency)
+            self._metrics.latencies_s.append(
+                done - client._arrived.pop(wnd, done))
         for client, campaign in chunk:
             client.campaigns_done += 1
             self._chains[client.qkey].advance(client.stream, anchor)
-        work = float(jnp.sum(res.edge_work))
         self._metrics.launches += 1
         self._metrics.lanes += len(windows)
         self._metrics.padded_lanes += bucket - len(windows)
         self._metrics.completed += len(windows)
-        self._metrics.edge_work += work
+        self._metrics.edge_work += stats.edge_work
         self._metrics.seeded_vertex_lanes += len(windows) * self.store.num_nodes
         self._metrics.unstable_vertex_lanes += int(
             jnp.sum(res.unstable[:len(windows)]))
         record = LaunchRecord(
             group=group, anchor=anchor, windows=windows,
             clients=[c.name for c in owners], lanes=len(windows),
-            bucket=bucket, anchor_events=events, edge_work=work,
-            iterations=int(jnp.max(res.iterations)))
+            bucket=bucket, anchor_events=events, edge_work=stats.edge_work,
+            iterations=stats.sweeps)
         self.launch_log.append(record)
         return record

@@ -14,6 +14,11 @@ the system that, at cluster scale, becomes a distributed sort/merge over the
 ingest pipeline; on one host numpy's merge-based set ops are the right tool).
 Device-side execution consumes only the padded immutable blocks.
 
+Host preparation runs in two spans (``repro.runtime.spans``):
+``cg.store.keys`` around set algebra (a ``window_keys`` miss, every
+``delta_keys``) and ``cg.store.blocks`` around building a block on a cache
+miss (key decode, weights, dst sort, padding, upload). Cache hits open none.
+
 Store contract (what every executor may assume):
 
 * **Pure cache.** Every block is a pure function of ``(seq, tag)``: evicting
@@ -65,6 +70,7 @@ from repro.graph.edgeset import (
     stack_delta_blocks,
 )
 from repro.graph.generators import EvolvingSequence
+from repro.runtime.spans import span
 
 
 def tightest_cover(candidates, window, size_fn):
@@ -330,10 +336,11 @@ class SnapshotStore:
         while (i, k) not in self._t:
             k -= 1
         cur = self._t[(i, k)]
-        for m in range(k + 1, j + 1):
-            cur = np.intersect1d(cur, self.seq.snapshot_keys[m],
-                                 assume_unique=True)
-            self._t[(i, m)] = cur
+        with span("cg.store.keys"):
+            for m in range(k + 1, j + 1):
+                cur = np.intersect1d(cur, self.seq.snapshot_keys[m],
+                                     assume_unique=True)
+                self._t[(i, m)] = cur
         return cur
 
     def window_size(self, i: int, j: int) -> int:
@@ -346,8 +353,10 @@ class SnapshotStore:
         ci, cj = child
         if not (pi <= ci and cj <= pj):
             raise ValueError(f"child window {child} not nested in parent {parent}")
-        return np.setdiff1d(self.window_keys(ci, cj), self.window_keys(pi, pj),
-                            assume_unique=True)
+        child_keys = self.window_keys(ci, cj)
+        parent_keys = self.window_keys(pi, pj)
+        with span("cg.store.keys"):
+            return np.setdiff1d(child_keys, parent_keys, assume_unique=True)
 
     # -- device blocks ---------------------------------------------------------
 
@@ -356,10 +365,11 @@ class SnapshotStore:
         blk = self._cache_get(tag)
         if blk is not None:
             return blk
-        src, dst = keys_to_edges(keys, self.num_nodes)
-        w = self.seq.weights_for(keys)
-        blk = make_block(src, dst, w, self.num_nodes, granule=self.granule,
-                         pad_pow2=self.pad_pow2)
+        with span("cg.store.blocks"):
+            src, dst = keys_to_edges(keys, self.num_nodes)
+            w = self.seq.weights_for(keys)
+            blk = make_block(src, dst, w, self.num_nodes,
+                             granule=self.granule, pad_pow2=self.pad_pow2)
         return self._cache_put(tag, blk)
 
     def window_block(self, i: int, j: int) -> EdgeBlock:
@@ -407,13 +417,16 @@ class SnapshotStore:
         blk = self._cache_get(tag)
         if blk is not None:
             return blk
-        lanes = []
-        for parent, child in hops:
-            keys = self.delta_keys(parent, child)
-            s, d = keys_to_edges(keys, self.num_nodes)
-            lanes.append((s, d, self.seq.weights_for(keys)))
-        blk = stack_delta_blocks(lanes, self.num_nodes, granule=self.granule,
-                                 pad_pow2=self.pad_pow2, num_lanes=num_lanes)
+        with span("cg.store.blocks"):
+            lanes = []
+            for parent, child in hops:
+                keys = self.delta_keys(parent, child)
+                s, d = keys_to_edges(keys, self.num_nodes)
+                lanes.append((s, d, self.seq.weights_for(keys)))
+            blk = stack_delta_blocks(lanes, self.num_nodes,
+                                     granule=self.granule,
+                                     pad_pow2=self.pad_pow2,
+                                     num_lanes=num_lanes)
         return self._cache_put(tag, blk)
 
     def snapshot_view(self, i: int) -> EdgeView:

@@ -58,6 +58,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.kickstarter import StreamStats
 from repro.core.snapshots import SnapshotStore
@@ -71,6 +72,7 @@ from repro.graph.engine import (
 )
 from repro.graph.semiring import Semiring
 from repro.graph.stability import stable_fraction_milli
+from repro.runtime.spans import span
 
 Window = tuple[int, int]
 
@@ -229,20 +231,63 @@ def _anchor_view(store, window, cg_split):
             else store.common_graph_view(*window))
 
 
+def _sweep_counts(stats: StreamStats, bucket: int, blocks, real: int,
+                  seed_blocks=None, seed_real: int = 0) -> dict:
+    """The counters of a fixpoint span: ``useful``, ``slots`` and ``pad``.
+
+    ``useful`` is the launch's ``edge_work``: relaxations from frontier
+    vertices over real edges, the seed sweep included. ``slots`` is the
+    edge slots its sweeps executed, from the block shapes and the fetched
+    sweep count: the seed sweep (when ``seed_blocks`` is given; it touches
+    only those blocks) plus ``stats.sweeps`` fixpoint sweeps (one fewer
+    when seeded) over ``blocks``. Under ``vmap`` every lane of the
+    ``bucket`` runs to the slowest lane's count, masked padding lanes
+    included, and fused chunks run the same count. With ``gated=True`` a
+    skipped block still counts, so ``slots`` is an upper bound there.
+    ``pad`` is the sentinel slots among ``slots``; ``real`` and
+    ``seed_real`` are the real edges per sweep over all lanes.
+
+    Host arithmetic on shapes and on numbers already copied back: no
+    device transfer. ``slots`` is therefore a model of today's dense sweep
+    (``engine._block_sweep`` reads every slot of every block each sweep),
+    not a count the device keeps: a change to which slots a sweep reads
+    changes this function in the same change.
+    """
+    def width(blks) -> int:   # a shared block is swept once per lane
+        return bucket * sum(int(b.src.shape[-1]) for b in blks)
+
+    sweeps = stats.sweeps
+    slots = pad = 0
+    if seed_blocks is not None:
+        sweeps -= 1
+        slots = width(seed_blocks)
+        pad = slots - seed_real
+    slots += sweeps * width(blocks)
+    pad += sweeps * (width(blocks) - real)
+    return {"useful": int(stats.edge_work), "slots": slots, "pad": pad}
+
+
 def _anchor_base(store, window, semiring, source, max_iters, gated, cg_split,
                  track_parents, fused_k=1):
     """Anchor-window fixpoint shared by all executors: (view, result, stats).
 
     The TG executors anchor at the plan apex; the sliding-window executors
-    (core/window.py) anchor at the windows' common super-window.
+    (core/window.py) anchor at the windows' common super-window. The span
+    ``cg.anchor.rebuild`` closes after the sync, so it holds the device
+    work.
     """
     t0 = time.perf_counter()
-    apex_view = _anchor_view(store, window, cg_split)
-    base = run_to_fixpoint(apex_view, semiring, source, max_iters, gated=gated,
-                           track_parents=track_parents, fused_k=fused_k)
-    host_sync(base.values)
-    base_stats = StreamStats(time.perf_counter() - t0, float(base.edge_work),
-                             int(base.iterations))
+    with span("cg.anchor.rebuild") as sp:
+        apex_view = _anchor_view(store, window, cg_split)
+        base = run_to_fixpoint(apex_view, semiring, source, max_iters,
+                               gated=gated, track_parents=track_parents,
+                               fused_k=fused_k)
+        host_sync(base.values)
+        base_stats = StreamStats(time.perf_counter() - t0,
+                                 float(base.edge_work), int(base.iterations))
+        if sp.live:
+            sp.set(**_sweep_counts(base_stats, 1, apex_view.blocks,
+                                   store.window_size(*window)))
     return apex_view, base, base_stats
 
 
@@ -400,40 +445,51 @@ def run_plan_batched(
     prev_values = base.values[None]
     prev_parent = base.parent[None]
     for level in plan_levels(plan):
-        t0 = time.perf_counter()
-        lanes = len(level)
-        bucket = lane_bucket(lanes, data_extent)
-        lane_layout.append((lanes, bucket))
-        hop_stacked = store.delta_stack(
-            [(prev_nodes[pi].window, c.window) for pi, c in level],
-            num_lanes=bucket)
-        if any(prev_nodes[pi].window != apex_window for pi, _ in level):
-            prefix_stacked = store.delta_stack(
-                [(apex_window, prev_nodes[pi].window) for pi, _ in level],
-                num_lanes=bucket)
-            delta_blocks = (prefix_stacked, hop_stacked)
-        else:
-            delta_blocks = (hop_stacked,)   # level 1: parents ARE the apex
+        with span("cg.launch") as sp:
+            t0 = time.perf_counter()
+            lanes = len(level)
+            bucket = lane_bucket(lanes, data_extent)
+            lane_layout.append((lanes, bucket))
+            hops = [(prev_nodes[pi].window, c.window) for pi, c in level]
+            hop_stacked = store.delta_stack(hops, num_lanes=bucket)
+            if any(parent != apex_window for parent, _ in hops):
+                prefix_stacked = store.delta_stack(
+                    [(apex_window, parent) for parent, _ in hops],
+                    num_lanes=bucket)
+                delta_blocks = (prefix_stacked, hop_stacked)
+            else:
+                delta_blocks = (hop_stacked,)   # level 1: parents ARE the apex
 
-        # Masked padding lanes re-run lane 0's parent state over an empty Δ:
-        # no frontier is ever seeded, values stay an inert copy, and
-        # lane_valid zeroes them out of the work accounting.
-        lane_map = [pi for pi, _ in level] + [0] * (bucket - lanes)
-        values, parent = gather_lane_states(prev_values, prev_parent, lane_map)
-        lane_valid = jnp.arange(bucket) < lanes
-        values, parent, delta_blocks, lane_valid = _shard_snapshot_axis(
-            mesh, values, parent, delta_blocks, lane_valid)
-        res = incremental_additions_batched(
-            n, semiring, values, parent,
-            shared_blocks=tuple(apex_view.blocks), delta_blocks=delta_blocks,
-            max_iters=max_iters, track_parents=track_parents, gated=gated,
-            seed_blocks=(delta_blocks[-1],), lane_valid=lane_valid, seed=seed,
-            fused_k=fused_k)
-        host_sync(res.values)
-        hop_stats.append(StreamStats(time.perf_counter() - t0,
-                                     float(jnp.sum(res.edge_work)),
-                                     int(jnp.max(res.iterations))))
-        unstable_counts.extend(int(u) for u in res.unstable[:lanes])
+            # Masked padding lanes re-run lane 0's parent state over an
+            # empty Δ: no frontier is ever seeded, values stay an inert
+            # copy, and lane_valid zeroes them out of the work accounting.
+            lane_map = [pi for pi, _ in level] + [0] * (bucket - lanes)
+            values, parent = gather_lane_states(prev_values, prev_parent,
+                                                lane_map)
+            lane_valid = jnp.arange(bucket) < lanes
+            values, parent, delta_blocks, lane_valid = _shard_snapshot_axis(
+                mesh, values, parent, delta_blocks, lane_valid)
+            res = incremental_additions_batched(
+                n, semiring, values, parent,
+                shared_blocks=tuple(apex_view.blocks),
+                delta_blocks=delta_blocks, max_iters=max_iters,
+                track_parents=track_parents, gated=gated,
+                seed_blocks=(delta_blocks[-1],), lane_valid=lane_valid,
+                seed=seed, fused_k=fused_k)
+            host_sync(res.values)
+            stats = StreamStats(time.perf_counter() - t0,
+                                float(jnp.sum(res.edge_work)),
+                                int(jnp.max(res.iterations)))
+            if sp.live:
+                size = store.window_size
+                apex = size(*apex_window)
+                sp.set(**_sweep_counts(
+                    stats, bucket, apex_view.blocks + delta_blocks,
+                    bucket * apex + sum(size(*c) - apex for _, c in hops),
+                    (delta_blocks[-1],),
+                    sum(size(*c) - size(*p) for p, c in hops)))
+        hop_stats.append(stats)
+        unstable_counts.extend(np.asarray(res.unstable)[:lanes])
         for lane, (_, c) in enumerate(level):
             if not c.children:
                 results[c.window[0]] = res.values[lane]

@@ -96,6 +96,7 @@ from repro.core.trigrid import (
     _anchor_base,
     _anchor_view,
     _shard_snapshot_axis,
+    _sweep_counts,
     hop_added_edges,
 )
 from repro.graph.edgeset import lane_bucket
@@ -109,6 +110,7 @@ from repro.graph.engine import (
 )
 from repro.graph.semiring import Semiring
 from repro.graph.stability import stable_fraction_milli
+from repro.runtime.spans import span
 
 Window = tuple[int, int]
 
@@ -281,15 +283,12 @@ def run_window_slide_batched(
         store, anchor, semiring, source, max_iters, gated, cg_split,
         track_parents, fused_k)
 
-    t0 = time.perf_counter()
-    res, bucket = _slide_launch(store, semiring, anchor_view,
-                                extract_state(base), windows, anchor,
-                                max_iters=max_iters, gated=gated,
-                                track_parents=track_parents, mesh=mesh,
-                                seed=seed, fused_k=fused_k)
-    hop_stats = [StreamStats(time.perf_counter() - t0,
-                             float(jnp.sum(res.edge_work)),
-                             int(jnp.max(res.iterations)))]
+    res, bucket, stats = _slide_launch(store, semiring, anchor_view,
+                                       extract_state(base), windows, anchor,
+                                       max_iters=max_iters, gated=gated,
+                                       track_parents=track_parents, mesh=mesh,
+                                       seed=seed, fused_k=fused_k)
+    hop_stats = [stats]
     results = {wnd: res.values[lane] for lane, wnd in enumerate(windows)}
     return WindowSlideRun(results, anchor, base_stats, hop_stats,
                           time.perf_counter() - t_all,
@@ -320,34 +319,49 @@ def _slide_launch(store: SnapshotStore, semiring: Semiring, anchor_view,
     ``lane_valid`` zeroes them out of the work accounting. The per-window
     slide Δs stack shape-bucketed, and one
     ``incremental_additions_batched`` call runs the lanes (sharded over
-    ``data`` when a mesh is given). Returns ``(FixpointResult, bucket)``.
+    ``data`` when a mesh is given).
+
+    Returns ``(FixpointResult, bucket, StreamStats)``: the stats hold the
+    launch's summed edge work and slowest lane's sweeps, copied to the
+    host once here, inside the launch's ``cg.launch`` span.
     """
-    data_extent = mesh.shape["data"] if mesh is not None else 1
-    bucket = lane_bucket(len(windows), data_extent)
-    stacked = store.slide_stack(windows, anchor, num_lanes=bucket)
-    if lane_map is None:
-        states, lane_map = [state], [0] * len(windows)
-    else:
-        states = list(state)
-        if len(lane_map) != len(windows):
-            raise ValueError(f"lane_map names {len(lane_map)} lanes for "
-                             f"{len(windows)} windows")
-    lane_map = list(lane_map) + [lane_map[0]] * (bucket - len(windows))
-    values, parent = gather_lane_states(
-        jnp.stack([s.values for s in states]),
-        jnp.stack([s.parent for s in states]), lane_map)
-    lane_valid = jnp.arange(bucket) < len(windows)
-    delta_blocks = (stacked,)
-    values, parent, delta_blocks, lane_valid = _shard_snapshot_axis(
-        mesh, values, parent, delta_blocks, lane_valid)
-    res = incremental_additions_batched(
-        store.num_nodes, semiring, values, parent,
-        shared_blocks=tuple(anchor_view.blocks), delta_blocks=delta_blocks,
-        max_iters=max_iters, track_parents=track_parents, gated=gated,
-        seed_blocks=(delta_blocks[-1],), lane_valid=lane_valid, seed=seed,
-        fused_k=fused_k)
-    host_sync(res.values)
-    return res, bucket
+    if lane_map is not None and len(lane_map) != len(windows):
+        raise ValueError(f"lane_map names {len(lane_map)} lanes for "
+                         f"{len(windows)} windows")
+    t0 = time.perf_counter()
+    with span("cg.launch") as sp:
+        data_extent = mesh.shape["data"] if mesh is not None else 1
+        bucket = lane_bucket(len(windows), data_extent)
+        stacked = store.slide_stack(windows, anchor, num_lanes=bucket)
+        if lane_map is None:
+            states, lane_map = [state], [0] * len(windows)
+        else:
+            states = list(state)
+        lane_map = list(lane_map) + [lane_map[0]] * (bucket - len(windows))
+        values, parent = gather_lane_states(
+            jnp.stack([s.values for s in states]),
+            jnp.stack([s.parent for s in states]), lane_map)
+        lane_valid = jnp.arange(bucket) < len(windows)
+        delta_blocks = (stacked,)
+        values, parent, delta_blocks, lane_valid = _shard_snapshot_axis(
+            mesh, values, parent, delta_blocks, lane_valid)
+        res = incremental_additions_batched(
+            store.num_nodes, semiring, values, parent,
+            shared_blocks=tuple(anchor_view.blocks), delta_blocks=delta_blocks,
+            max_iters=max_iters, track_parents=track_parents, gated=gated,
+            seed_blocks=(delta_blocks[-1],), lane_valid=lane_valid, seed=seed,
+            fused_k=fused_k)
+        host_sync(res.values)
+        stats = StreamStats(time.perf_counter() - t0,
+                            float(jnp.sum(res.edge_work)),
+                            int(jnp.max(res.iterations)))
+        if sp.live:
+            base = store.window_size(*anchor)
+            added = sum(store.window_size(*w) - base for w in windows)
+            sp.set(**_sweep_counts(stats, bucket,
+                                   anchor_view.blocks + delta_blocks,
+                                   bucket * base + added, delta_blocks, added))
+    return res, bucket, stats
 
 
 # ---------------------------------------------------------------------------
@@ -787,7 +801,8 @@ def _acquire_anchor_state(store: SnapshotStore, qkey: tuple, anchor: Window,
     on which path ran. The acquired state is (re-)cached under the anchor's
     "AS" tag. ``fused_k`` only shapes the hop/rebuild launches (bit-identical
     states at any value), which is why it is a launch option and NOT part of
-    ``qkey`` — states stay shareable across fused chunk sizes.
+    ``qkey`` — states stay shareable across fused chunk sizes. A hop runs in
+    the span ``cg.anchor.hop``, a rebuild in ``cg.anchor.rebuild``.
     """
     t0 = time.perf_counter()
     state = store.anchor_state_get(qkey, anchor)
@@ -798,20 +813,23 @@ def _acquire_anchor_state(store: SnapshotStore, qkey: tuple, anchor: Window,
     cover = store.anchor_state_cover(qkey, anchor)
     if cover is not None:
         cover_window, cover_state = cover
-        delta = store.delta_block(cover_window, anchor)
-        view = _anchor_view(store, cover_window, cg_split).extended(delta)
-        res = incremental_additions(view, delta, semiring, cover_state.values,
-                                    cover_state.parent, max_iters,
-                                    gated=gated, track_parents=track_parents,
-                                    seed=seed, fused_k=fused_k)
-        host_sync(res.values)
+        with span("cg.anchor.hop") as sp:
+            delta = store.delta_block(cover_window, anchor)
+            view = _anchor_view(store, cover_window, cg_split).extended(delta)
+            res = incremental_additions(
+                view, delta, semiring, cover_state.values, cover_state.parent,
+                max_iters, gated=gated, track_parents=track_parents,
+                seed=seed, fused_k=fused_k)
+            host_sync(res.values)
+            stats = StreamStats(time.perf_counter() - t0,
+                                float(res.edge_work), int(res.iterations))
+            size = store.window_size(*anchor)
+            delta_edges = size - store.window_size(*cover_window)
+            if sp.live:
+                sp.set(**_sweep_counts(stats, 1, view.blocks, size, (delta,),
+                                       delta_edges))
         state = store.anchor_state_put(qkey, anchor, extract_state(res))
-        delta_edges = (store.window_size(*anchor)
-                       - store.window_size(*cover_window))
-        return view, state, StreamStats(time.perf_counter() - t0,
-                                        float(res.edge_work),
-                                        int(res.iterations)), "hop", \
-            delta_edges
+        return view, state, stats, "hop", delta_edges
     anchor_view, base, base_stats = _anchor_base(
         store, anchor, semiring, source, max_iters, gated, cg_split,
         track_parents, fused_k)
@@ -1131,14 +1149,11 @@ def run_window_stream_batched(
         anchor_events.append(event)
         anchor_stats.append(stats)
         anchor_delta_edges += delta_edges
-        t0 = time.perf_counter()
-        res, bucket = _slide_launch(store, semiring, anchor_view, state,
-                                    campaign, anchor, max_iters=max_iters,
-                                    gated=gated, track_parents=track_parents,
-                                    mesh=mesh, seed=seed, fused_k=fused_k)
-        hop_stats.append(StreamStats(time.perf_counter() - t0,
-                                     float(jnp.sum(res.edge_work)),
-                                     int(jnp.max(res.iterations))))
+        res, bucket, stats = _slide_launch(
+            store, semiring, anchor_view, state, campaign, anchor,
+            max_iters=max_iters, gated=gated, track_parents=track_parents,
+            mesh=mesh, seed=seed, fused_k=fused_k)
+        hop_stats.append(stats)
         lane_layout.append((len(campaign), bucket))
         unstable_counts.append(np.asarray(res.unstable)[:len(campaign)])
         for lane, wnd in enumerate(campaign):
