@@ -528,7 +528,7 @@ class IngestCutDiscipline(Rule):
         "that bookkeeping (metrics, sealing, common-graph maintenance); "
         "growing the live sequence directly (.snapshot_keys/.additions/"
         ".deletions .append) desynchronizes the store's window cache from "
-        "its sequence; and writing the store's _t/_blocks caches from "
+        "its sequence; and writing the store's _t/_deltas/_blocks caches from "
         "outside core/snapshots.py plants entries the pure-cache contract "
         "cannot rebuild. All three are flagged outside their one legal "
         "home (ingest.Watermark.cut / ingest.LiveSequence.append / the "
@@ -539,7 +539,7 @@ class IngestCutDiscipline(Rule):
     INGEST_MODULE = "repro.core.ingest"
     SANCTIONED_FN = "cut"
     GROW_ATTRS = ("snapshot_keys", "additions", "deletions")
-    CACHE_ATTRS = ("_t", "_blocks")
+    CACHE_ATTRS = ("_t", "_deltas", "_blocks")
 
     def check(self, module: Module) -> Iterator[Finding]:
         dotted = module.dotted_name()

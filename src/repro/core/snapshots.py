@@ -15,9 +15,11 @@ ingest pipeline; on one host numpy's merge-based set ops are the right tool).
 Device-side execution consumes only the padded immutable blocks.
 
 Host preparation runs in two spans (``repro.runtime.spans``):
-``cg.store.keys`` around set algebra (a ``window_keys`` miss, every
-``delta_keys``) and ``cg.store.blocks`` around building a block on a cache
-miss (key decode, weights, dst sort, padding, upload). Cache hits open none.
+``cg.store.keys`` around set algebra (a ``window_keys`` or ``delta_keys``
+miss) and ``cg.store.blocks`` around building a block on a cache miss (key
+decode, weights, dst sort, padding, upload). Cache hits open none. A
+``delta_stack`` miss's ``cg.store.blocks`` span counts its lanes whose Δ
+keys came from the memo (``delta_hit``) and were computed (``delta_miss``).
 
 Store contract (what every executor may assume):
 
@@ -39,6 +41,12 @@ Store contract (what every executor may assume):
   width)`` and the lane axis always divides a mesh's ``data`` extent.
   Host-side key arrays (``window_keys``) are never evicted — they are the
   cheap part and keep rebuilds exact.
+* **Δ key memo.** ``delta_keys`` computes each (parent, child) hop's
+  addition keys once and returns the same read-only array on every later
+  call: a pure function of the two windows' keys, so every Δ block and
+  answer is unchanged. Like the window keys it is host-side and never
+  evicted by ``cache_bytes`` or ``release``; ``compact`` drops the entries
+  whose parent window reaches below the new horizon.
 * **Anchor-state family ("AS" tags).** Converged anchor query states
   (``QueryState``) live in the SAME LRU alongside edge blocks — the first
   cross-launch reuse: a streaming campaign scheduler (core/window.py) seeds
@@ -178,6 +186,7 @@ class SnapshotStore:
         self._t: dict[tuple[int, int], np.ndarray] = {
             (i, i): seq.snapshot_keys[i] for i in range(seq.num_snapshots)
         }
+        self._deltas: dict[tuple, np.ndarray] = {}   # (parent, child) -> Δ keys
         self._blocks: OrderedDict[tuple, EdgeBlock] = OrderedDict()
         self._cached_nbytes = 0
         self._pins: dict[tuple, int] = {}   # tag -> refcount (see pin())
@@ -348,15 +357,25 @@ class SnapshotStore:
         return int(self.window_keys(i, j).shape[0])
 
     def delta_keys(self, parent: tuple[int, int], child: tuple[int, int]) -> np.ndarray:
-        """Edges added when descending T(parent) → T(child); child ⊆ parent window."""
+        """Edges added when descending T(parent) → T(child); child ⊆ parent window.
+
+        Memoised per (parent, child): the first call computes the set
+        difference, every later one returns the same read-only array.
+        """
         pi, pj = parent
         ci, cj = child
         if not (pi <= ci and cj <= pj):
             raise ValueError(f"child window {child} not nested in parent {parent}")
-        child_keys = self.window_keys(ci, cj)
-        parent_keys = self.window_keys(pi, pj)
-        with span("cg.store.keys"):
-            return np.setdiff1d(child_keys, parent_keys, assume_unique=True)
+        memo = (tuple(parent), tuple(child))
+        keys = self._deltas.get(memo)
+        if keys is None:
+            child_keys = self.window_keys(ci, cj)
+            parent_keys = self.window_keys(pi, pj)
+            with span("cg.store.keys"):
+                keys = np.setdiff1d(child_keys, parent_keys, assume_unique=True)
+            keys.flags.writeable = False
+            self._deltas[memo] = keys
+        return keys
 
     # -- device blocks ---------------------------------------------------------
 
@@ -417,8 +436,8 @@ class SnapshotStore:
         blk = self._cache_get(tag)
         if blk is not None:
             return blk
-        with span("cg.store.blocks"):
-            lanes = []
+        with span("cg.store.blocks") as sp:
+            lanes, memo_size = [], len(self._deltas)
             for parent, child in hops:
                 keys = self.delta_keys(parent, child)
                 s, d = keys_to_edges(keys, self.num_nodes)
@@ -427,6 +446,9 @@ class SnapshotStore:
                                      granule=self.granule,
                                      pad_pow2=self.pad_pow2,
                                      num_lanes=num_lanes)
+            if sp.live:     # each computed Δ adds one memo entry
+                misses = len(self._deltas) - memo_size
+                sp.set(delta_hit=len(hops) - misses, delta_miss=misses)
         return self._cache_put(tag, blk)
 
     def snapshot_view(self, i: int) -> EdgeView:
@@ -524,9 +546,10 @@ class SnapshotStore:
         hop from it, and the hop's Δ keys need the anchor window's
         intersection. Snapshots below the clamped horizon are freed
         (host key/Δ arrays become ``None`` placeholders so absolute
-        indices never shift), stale window-cache entries and device
-        blocks referencing them are purged (pinned tags skipped — they are
-        unreachable only until unpinned), and ``first_live`` advances.
+        indices never shift), stale window-cache entries, memoised Δ keys
+        and device blocks referencing them are purged (pinned tags
+        skipped — they are unreachable only until unpinned), and
+        ``first_live`` advances.
         Requires a mutable live sequence, like :meth:`ingest_cut`.
         """
         seq = self.seq
@@ -555,6 +578,8 @@ class SnapshotStore:
         if retired:
             for w in [w for w in self._t if w[0] < horizon]:
                 del self._t[w]
+            for hop in [hop for hop in self._deltas if hop[0][0] < horizon]:
+                del self._deltas[hop]   # the parent is the wider window
             for tag in list(self._blocks):
                 low = _tag_min_index(tag)
                 if low is not None and low < horizon \

@@ -551,6 +551,16 @@ def test_g009_canonical_module_exempt_for_cache_writes(tmp_path):
                         rules=[get_rule("G009")]) == []
 
 
+def test_g009_flags_a_planted_delta_memo_entry(tmp_path):
+    code = ("def plant_delta(store, parent, child, keys):\n"
+            "    store._deltas[(parent, child)] = keys\n")
+    findings = lint_snippet(tmp_path, code,
+                            relpath="src/repro/launch/firehose.py",
+                            rules=[get_rule("G009")])
+    assert_only_rule(findings, "G009", count=1)
+    assert "SnapshotStore._deltas" in findings[0].message
+
+
 # -- G010: fused-launch discipline --------------------------------------------
 
 BAD_G010 = """\
