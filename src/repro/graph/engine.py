@@ -1,14 +1,23 @@
 """Frontier-masked edge-relaxation fixpoint engine (TPU-native KickStarter core).
 
-One *sweep* is a dense Bellman-Ford-style round over an edge view:
+One *sweep* is a dense Bellman-Ford-style round over an edge view: mask
+values by frontier (V elements), gather once, combine, segment-reduce.
 
-    cand[e]  = combine(values[src[e]], w[e])        (masked to the frontier)
-    best[v]  = segment_reduce(cand, dst)            (min or max semiring)
-    values'  = meet(values, best);  frontier' = strictly-improved vertices
+    masked[u] = values[u] if frontier[u] else identity
+    cand[e]   = combine(masked[src[e]], w[e])       (one gather per edge slot)
+    best[v]   = segment_reduce(cand, dst)           (min or max semiring)
+    values'   = meet(values, best);  frontier' = strictly-improved vertices
+
+This is exactly the frontier-masked sweep: ``combine(identity, w) ==
+identity`` for all five semirings, so an inactive edge's candidate is the
+identity either way, and ``frontier[src]`` is never gathered. The edge work
+(real edges whose source is in the frontier) is the frontier's dot product
+with the view's real out-degree, which a launch computes once
+(:func:`_out_degree`).
 
 Monotone semirings make the dense sweep idempotent and order-free, which is
 what lets us replace the CPU papers' per-vertex worklists + atomics with
-segment reductions (DESIGN.md §2). ``parent[v]`` tracks the dependence edge
+segment reductions. ``parent[v]`` tracks the dependence edge
 source that produced ``values[v]`` — the KickStarter trimming baseline
 (core/kickstarter.py) consumes it on deletions.
 
@@ -115,30 +124,50 @@ def _segment_reduce(sr: Semiring, data, segment_ids, num_segments):
     return jax.ops.segment_max(data, segment_ids, num_segments)
 
 
-def _block_sweep(semiring: Semiring, num_nodes: int, values, frontier,
-                 src, dst, w, track_parents: bool = True):
-    """One block's (best, winner_src, work) against the current frontier.
+def _out_degree(num_nodes: int, blocks: Blocks) -> jnp.ndarray:
+    """Real out-degree of every vertex over ``blocks`` (int32 [num_nodes]).
+
+    Padding slots (``dst == num_nodes``) count for nothing. A sweep's edge
+    work is this vector summed over the frontier, so the fixpoint computes
+    it once per launch, outside its sweep loop; under ``vmap`` the shared
+    blocks' part does not depend on the lane and stays unbatched.
+    """
+    deg = jnp.zeros((num_nodes,), jnp.int32)
+    for src, dst, _ in blocks:
+        deg = deg + jax.ops.segment_sum((dst < num_nodes).astype(jnp.int32),
+                                        src, num_nodes)
+    return deg
+
+
+def _block_sweep(semiring: Semiring, num_nodes: int, masked, src, dst, w,
+                 track_parents: bool = True):
+    """One block's (best, winner_src) from the frontier-masked values.
+
+    ``masked`` is ``values`` with every vertex outside the frontier set to
+    the identity, so the sweep gathers once per edge slot: an inactive
+    source gives ``combine(identity, w) == identity``, the candidate the
+    two-gather form (``frontier[src]`` and ``values[src]``) gave it.
 
     ``track_parents=False`` (CommonGraph mode): dependence parents exist
     solely so KickStarter can trim on *deletions*. Deletion-free schedules
     (Direct-Hop / TG work-sharing) never trim, so the winner-src segment
     reduce — half the per-sweep segment ops — is skipped entirely. This is
     the paper's "deletions are what make streaming expensive" claim showing
-    up inside the engine itself (EXPERIMENTS.md §Perf).
+    up inside the engine itself.
     """
     ident = jnp.float32(semiring.identity)
-    active = frontier[src]  # pad edges read frontier[PAD_SRC]; their dst is the sentinel
-    cand = jnp.where(active, semiring.combine(values[src], w), ident)
+    # pad edges read masked[PAD_SRC]; their dst is the sentinel segment
+    cand = semiring.combine(masked[src], w)
     blk_best = _segment_reduce(semiring, cand, dst, num_nodes + 1)[:num_nodes]
-    work = jnp.sum(active & (dst < num_nodes), dtype=jnp.float32)
     if not track_parents:
-        return blk_best, None, work
-    # smallest src achieving this block's best (merged across blocks by caller)
+        return blk_best, None
+    # smallest src achieving this block's best (merged across blocks by
+    # caller). Only improved vertices keep a winner, and their best is not
+    # the identity, so no inactive edge (candidate: the identity) can tie.
     best_pad = jnp.concatenate([blk_best, jnp.float32([ident])])
-    is_win = active & (cand == best_pad[dst])
-    parent_cand = jnp.where(is_win, src, INT_MAX)
+    parent_cand = jnp.where(cand == best_pad[dst], src, INT_MAX)
     blk_winner = jax.ops.segment_min(parent_cand, dst, num_nodes + 1)[:num_nodes]
-    return blk_best, blk_winner, work
+    return blk_best, blk_winner
 
 
 def relax_sweep(
@@ -150,40 +179,46 @@ def relax_sweep(
     blocks: Blocks,
     gated: bool = False,
     track_parents: bool = True,
+    out_deg: jnp.ndarray | None = None,
 ):
     """One frontier-masked relaxation sweep over all blocks.
 
-    ``gated`` (beyond-paper optimization, EXPERIMENTS.md §Perf): a block
-    whose sources contain no frontier vertex is skipped entirely via
-    lax.cond — the TPU-dense analogue of the CPU papers' per-vertex
-    worklists at edge-block granularity. Exactness is unaffected (skipped
-    blocks can only produce identity candidates).
+    Masks the values by the frontier once (V elements), then gathers each
+    block's masked values once per edge slot (:func:`_block_sweep`);
+    exact because the identity is absorbing under ``combine``. ``work``
+    is ``out_deg`` (default: :func:`_out_degree` of ``blocks``) summed over
+    the frontier: the real edges leaving frontier vertices.
+
+    ``gated`` (beyond-paper optimization): a block whose sources contain
+    no frontier vertex is skipped entirely via lax.cond — the TPU-dense
+    analogue of the CPU papers' per-vertex worklists at edge-block
+    granularity. Exactness is unaffected (skipped blocks can only produce
+    identity candidates).
 
     Returns (values, parent, improved, work).
     """
     ident = jnp.float32(semiring.identity)
+    if out_deg is None:
+        out_deg = _out_degree(num_nodes, blocks)
+    masked = jnp.where(frontier, values, ident)
     best = jnp.full((num_nodes,), ident)
     bests = []
-    work = jnp.float32(0)
     for src, dst, w in blocks:
         if gated:
             none_winner = (jnp.full((num_nodes,), INT_MAX, dtype=jnp.int32)
                            if track_parents else None)
-            blk_best, blk_winner, dw = jax.lax.cond(
+            blk_best, blk_winner = jax.lax.cond(
                 jnp.any(frontier[src]),
                 lambda s=src, d=dst, ww=w: _block_sweep(
-                    semiring, num_nodes, values, frontier, s, d, ww,
-                    track_parents),
-                lambda: (jnp.full((num_nodes,), ident), none_winner,
-                         jnp.float32(0)),
+                    semiring, num_nodes, masked, s, d, ww, track_parents),
+                lambda: (jnp.full((num_nodes,), ident), none_winner),
             )
         else:
-            blk_best, blk_winner, dw = _block_sweep(
-                semiring, num_nodes, values, frontier, src, dst, w,
-                track_parents)
+            blk_best, blk_winner = _block_sweep(
+                semiring, num_nodes, masked, src, dst, w, track_parents)
         best = semiring.better(best, blk_best)
         bests.append((blk_best, blk_winner))
-        work = work + dw
+    work = jnp.sum(jnp.where(frontier, out_deg, 0)).astype(jnp.float32)
 
     improved = semiring.strictly_better(best, values)
     new_values = semiring.better(values, best)
@@ -215,6 +250,7 @@ def relax_sweep_fused(
     track_parents: bool = True,
     use_pallas: bool = False,
     interpret: bool | None = None,
+    out_deg: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Up to ``k`` frontier-masked sweeps as one fused chunk.
 
@@ -232,6 +268,9 @@ def relax_sweep_fused(
       VMEM-resident across all sweeps with an on-chip early exit. It runs
       in the Pallas interpreter on the CPU backend (``interpret=None``) and
       does not compile for TPU v5e yet, so ``_fixpoint`` never selects it.
+
+    ``out_deg`` is the blocks' :func:`_out_degree`, computed here once per
+    chunk when the caller has not hoisted it already.
 
     graphlint rule G010 grants this call to ``graph.stability`` seeding and
     the engine's ``_fixpoint`` only — everything else reaches fused sweeps
@@ -251,6 +290,8 @@ def relax_sweep_fused(
                            op=KERNEL_OP_FOR[semiring.name],
                            num_nodes=num_nodes, k=k,
                            track_parents=track_parents, interpret=interpret)
+    if out_deg is None:
+        out_deg = _out_degree(num_nodes, blocks)
 
     def cond(state):
         _, _, frontier, s, _ = state
@@ -260,7 +301,7 @@ def relax_sweep_fused(
         values, parent, frontier, s, work = state
         values, parent, improved, dw = relax_sweep(
             semiring, num_nodes, values, parent, frontier, blocks,
-            gated=gated, track_parents=track_parents)
+            gated=gated, track_parents=track_parents, out_deg=out_deg)
         return values, parent, improved, s + 1, work + dw
 
     init = (values, parent, frontier, jnp.int32(0), jnp.float32(0))
@@ -274,6 +315,10 @@ def _fixpoint(semiring: Semiring, num_nodes: int, max_iters: int,
     def cond(state):
         _, _, frontier, it, _ = state
         return jnp.logical_and(it < max_iters, jnp.any(frontier))
+
+    # hoisted out of the sweep loop: one out-degree scatter per block per
+    # launch instead of a second per-slot gather in every sweep
+    out_deg = _out_degree(num_nodes, blocks)
 
     if fused_k > 1:
         # Consume fused chunks: each outer step advances up to fused_k
@@ -289,7 +334,7 @@ def _fixpoint(semiring: Semiring, num_nodes: int, max_iters: int,
             values, parent, frontier, s, dw = relax_sweep_fused(
                 semiring, num_nodes, values, parent, frontier, blocks,
                 k=fused_k, allowed=cap, gated=gated,
-                track_parents=track_parents)
+                track_parents=track_parents, out_deg=out_deg)
             return values, parent, frontier, it + s, work + dw
 
         init = (values, parent, frontier, jnp.int32(0), jnp.float32(0))
@@ -301,7 +346,7 @@ def _fixpoint(semiring: Semiring, num_nodes: int, max_iters: int,
         values, parent, frontier, it, work = state
         values, parent, improved, dw = relax_sweep(
             semiring, num_nodes, values, parent, frontier, blocks, gated=gated,
-            track_parents=track_parents)
+            track_parents=track_parents, out_deg=out_deg)
         return values, parent, improved, it + 1, work + dw
 
     init = (values, parent, frontier, jnp.int32(0), jnp.float32(0))
